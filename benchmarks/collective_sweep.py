@@ -56,10 +56,10 @@ def child_main(payloads_mb, reps: int) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch import mesh as mesh_lib
+    from repro.roofline.bench_schema import device_fields
 
     n = jax.device_count()
     mesh = mesh_lib.make_federation_mesh(
@@ -67,8 +67,8 @@ def child_main(payloads_mb, reps: int) -> dict:
     K = ROWS_PER_SHARD * n
 
     def shmap(body):
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P("vehicle"),),
-                                 out_specs=P("vehicle"), check_rep=False))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("vehicle"),),
+                                     out_specs=P("vehicle"), check_vma=False))
 
     def gather(x):                       # [K/n, cols] -> [K, cols]
         return jax.lax.all_gather(x, "vehicle", axis=0, tiled=True)
@@ -142,7 +142,7 @@ def child_main(payloads_mb, reps: int) -> dict:
         "benchmark": "collective_sweep",
         "workload": f"[{K}, cols] f32 over a {n}-shard vehicle mesh axis, "
                     f"best of {reps}",
-        "device_count": n,
+        **device_fields(),
         "axis_size": n,
         "num_leaves": NUM_LEAVES,
         "results": results,
@@ -158,7 +158,7 @@ def run(payloads_mb, reps: int, devices: int,
         out_path: str = "BENCH_collective.json") -> dict:
     repo_root = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # forced host devices, even beside a chip
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}"
                         ).strip()

@@ -18,12 +18,11 @@ from __future__ import annotations
 import json
 import time
 
-import jax
-
 from repro.data.synthetic import synthetic_mnist
 from repro.fed import backends as backends_lib
 from repro.fed import engine as engine_lib
 from repro.roofline import scenario_cost
+from repro.roofline.bench_schema import device_fields
 
 VEHICLE_COUNTS = (8, 64)
 
@@ -60,7 +59,7 @@ def main() -> dict:
     return {
         "benchmark": "engine_backends",
         "workload": "synthetic_mnist dds E=1 B=4 steady-state",
-        "device_count": jax.device_count(),
+        **device_fields(),
         "results": results,
     }
 

@@ -55,6 +55,7 @@ def child_main(k: int, contact_format: str, epochs: int) -> dict:
     from repro.fed import engine as engine_lib
     from repro.fed import topology
     from repro.roofline import scenario_cost
+    from repro.roofline.bench_schema import device_fields
 
     # the fleet covers a road net sized to the paper's density: ~1 vehicle
     # per junction, so contact sets (D_max) stay roughly constant with K
@@ -93,13 +94,14 @@ def child_main(k: int, contact_format: str, epochs: int) -> dict:
         "peak_rss_mb": round(
             resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024, 1),
         "contact_window_mb": round(window_mb, 3),
+        "device": device_fields(),
     }
 
 
 def run_cells(ks, out_path: str = "BENCH_scale.json") -> dict:
     repo_root = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # the cells are CPU measurements by design
     # pin the glibc malloc arena count: multi-arena growth is the dominant
     # run-to-run RSS noise and would swamp the contact-window delta
     env.setdefault("MALLOC_ARENA_MAX", "2")
@@ -118,6 +120,7 @@ def run_cells(ks, out_path: str = "BENCH_scale.json") -> dict:
                     f"engine_scale cell K={k} {fmt} failed:\n"
                     + proc.stderr[-4000:])
             results.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+            device = results[-1].pop("device")  # the same in every cell
             print(f"# K={k} {fmt}: "
                   f"{results[-1]['epochs_per_s']:.3f} epochs/s, "
                   f"{results[-1]['peak_rss_mb']:.0f} MB peak", flush=True)
@@ -138,6 +141,7 @@ def run_cells(ks, out_path: str = "BENCH_scale.json") -> dict:
         "benchmark": "engine_scale",
         "workload": "synthetic_mnist dds (p1_steps=200) E=1 B=1 steady-state, "
                     "one scan window, paper-density scale_grid road net",
+        **device,
         "results": results,
         "sparse_vs_dense": ratios,
     }

@@ -1,11 +1,11 @@
 """Kernel microbenchmarks (beyond-paper): us_per_call for the three Pallas
-kernels' jnp reference paths on CPU + interpret-mode validation overhead,
-plus the fused-engine vs legacy-loop epochs/sec comparison and the
-vmap-vs-shard_map backend comparison (which also writes the machine-readable
-``BENCH_engine.json`` so the perf trajectory is tracked per PR).
+kernels' jnp reference paths, plus the fused-engine vs legacy-loop
+epochs/sec comparison and the vmap-vs-shard_map backend comparison (which
+also writes the machine-readable ``BENCH_engine.json``).
 
-On-TPU numbers come from the same harness with interpret=False on a real
-device; here the CSV records the CPU reference timing and derived bandwidth.
+The first CSV row names the device the in-process rows ran on. The backend
+comparison always runs on forced CPU host devices in a child process, and
+its report says so.
 """
 from __future__ import annotations
 
@@ -27,6 +27,7 @@ from repro.fed.simulator import SimulationConfig
 from repro.kernels.flash_attention import flash_attention_ref
 from repro.kernels.gossip_mix import gossip_mix_matmul_ref
 from repro.kernels.kl_simplex import kl_rows_ref
+from repro.roofline.bench_schema import device_fields
 
 from .common import csv_row
 
@@ -42,7 +43,9 @@ def _time(fn, *args, iters=10) -> float:
 
 
 def main() -> list[str]:
-    rows = [csv_row("name", "us_per_call", "derived")]
+    dev = device_fields()
+    rows = [csv_row("name", "us_per_call", "derived"),
+            csv_row("device", dev["platform"], dev["device_kind"])]
     r = np.random.default_rng(0)
 
     k, p = 64, 1 << 20
@@ -77,27 +80,27 @@ def main() -> list[str]:
 def engine_backend_rows(out_path: str = "BENCH_engine.json",
                         forced_devices: int = 4) -> list[str]:
     """vmap vs shard_map epochs/sec at K in {8, 64} (benchmarks
-    .engine_backends), run in a CHILD process so the host-device count can
-    be forced after this process already initialized jax single-device.
-    Writes ``BENCH_engine.json`` at the repo root (where the tracked copy
-    lives, regardless of the invoking CWD) and returns CSV rows.
+    .engine_backends), run in a CHILD process on ``forced_devices`` CPU host
+    devices: the device count is forced there, after this process already
+    initialized jax, and the CPU is set explicitly so that a parent holding
+    a chip never leaves the child to find the CPU by accident. Writes
+    ``BENCH_engine.json`` at the repo root (where the tracked copy lives,
+    regardless of the invoking CWD) and returns CSV rows. A failed or
+    timed-out child raises.
     """
     repo_root = pathlib.Path(__file__).resolve().parent.parent
     env = dict(os.environ,
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
                           f" --xla_force_host_platform_device_count={forced_devices}").strip())
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = f"{repo_root / 'src'}{os.pathsep}" + env.get("PYTHONPATH", "")
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.engine_backends"],
-            env=env, capture_output=True, text=True, timeout=1800,
-            cwd=repo_root)
-    except subprocess.TimeoutExpired:
-        return [csv_row("engine_backends", "FAILED", "timeout_1800s")]
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.engine_backends"],
+        env=env, capture_output=True, text=True, timeout=1800,
+        cwd=repo_root)
     if proc.returncode != 0:
-        err = (proc.stderr.strip().splitlines() or ["?"])[-1]
-        return [csv_row("engine_backends", "FAILED", err[:120])]
+        raise RuntimeError("engine_backends child failed:\n"
+                           + proc.stderr[-4000:])
     report = json.loads(proc.stdout.strip().splitlines()[-1])
     out_file = repo_root / out_path
     out_file.write_text(json.dumps(report, indent=2) + "\n")
@@ -114,7 +117,7 @@ def engine_backend_rows(out_path: str = "BENCH_engine.json",
             f"{r['shard_map_epochs_per_s']:.2f}epochs_per_s"))
         rows.append(csv_row(f"engine_shard_vs_vmap_{k}v",
                             f"{r['shard_vs_vmap']:.2f}x",
-                            f"{report['device_count']}dev"))
+                            f"{report['device_count']}x{report['platform']}"))
     rows.append(csv_row("engine_backends_json", str(out_file), "machine_readable"))
     return rows
 

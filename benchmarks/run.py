@@ -23,6 +23,7 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import campaign as campaign_lib
 
 from . import (common, engine_scale, fig2_cdf, fig3_correlation, fig6_7_cifar,
@@ -97,6 +98,7 @@ def main() -> None:
     ap.add_argument("--strict", action="store_true",
                     help="exit non-zero if any ordering check fails")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.campaign:
         if args.results_md is None:

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import assigned_architectures, get_config
 from repro.launch import steps as steps_lib
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--vehicles", type=int, default=4)
     ap.add_argument("--rounds", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
                 ("vehicle", "fsdp", "model"))

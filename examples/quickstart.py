@@ -16,6 +16,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.synthetic import synthetic_mnist
 from repro.fed.simulator import SimulationConfig, run_simulation
 
@@ -25,6 +26,7 @@ def main(argv=None) -> float:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny settings so the run finishes in seconds")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = SimulationConfig(
         algorithm="dds",          # the paper's algorithm ("dfl" / "sp" = baselines)

@@ -21,6 +21,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.synthetic import synthetic_mnist
 from repro.fed.simulator import SimulationConfig
 from repro.launch.sweep import SweepSpec, run_sweep, summary_rows
@@ -31,6 +32,7 @@ def main(argv=None) -> list:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny settings so the run finishes in seconds")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     base = SimulationConfig(
         num_vehicles=6 if args.smoke else 8,

@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import assigned_architectures, get_config
 from repro.models import multimodal, transformer
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny settings so the run finishes in seconds")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.batch, args.prompt_len, args.gen = 1, 8, 4
 

@@ -17,6 +17,7 @@ import numpy as np
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.synthetic import synthetic_mnist
 from repro.fed import metrics
 from repro.fed.simulator import SimulationConfig, run_simulation
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--vehicles", type=int, default=24)
     ap.add_argument("--road-net", default="grid")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ds = synthetic_mnist(n_train=24_000, n_test=2_000)
     results = {}

@@ -27,7 +27,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import contacts as contacts_lib
@@ -98,6 +97,7 @@ def _drive_windows(ctx, window_fn, progress: bool):
         engine_lib._append_window(result, traj, mask, start, cfg.num_vehicles,
                                   progress)
     result.wall_time = time.time() - t0
+    result.final_state = state
     return result
 
 
@@ -258,11 +258,11 @@ class ShardMapBackend(Backend):
             "comm_mb": P(),                   # replicated [K, K] matrices
             "loss": P(),
         }
-        window = shard_map(
+        window = jax.shard_map(
             engine_lib.build_window_fn(sctx), mesh=mesh,
             in_specs=(state_spec, P(), data_spec, P(), contact_spec, P()),
             out_specs=(state_spec, P(), traj_spec),
-            check_rep=False)
+            check_vma=False)
         ctx._jit_cache["shard_window"] = jax.jit(window)
         return ctx._jit_cache["shard_window"]
 

@@ -93,8 +93,9 @@ class SimulationConfig:
     # Overflowing an explicit budget is a loud error, never a truncation.
     d_max: int = 0
     contact_density: float | None = None
-    # how the gossip mix W @ w executes: "jnp" (tensordot reference, the CPU
-    # default) | "pallas" (the gossip_mix TPU kernels; jnp fallback off-TPU)
+    # how the gossip mix W @ w executes: "jnp" (XLA tensordot / slot scan,
+    # the default) | "pallas" (the gossip_mix kernels: compiled on a TPU, in
+    # interpret mode elsewhere; never the jnp path in disguise)
     mixing_backend: str = "jnp"
     # communication/compute overlap (docs/SCALING.md "Overlap & multi-host"):
     # comm_bucket_mb packs the sharded mix's flattened param leaves into
@@ -163,10 +164,15 @@ class SimulationResult:
     # and the communication volume of that round's V2V exchanges in MB
     kl_trace: list[float] = field(default_factory=list)
     comm_mb: list[float] = field(default_factory=list)
+    # mean local-training loss over the federation, every global epoch
+    loss_trace: list[float] = field(default_factory=list)
     wall_time: float = 0.0
     # set when cfg.execution == "auto": the cost-model plan this run resolved
     # to (chosen knobs, predicted epochs/s, per-candidate breakdowns)
     execution_plan: dict | None = None
+    # the federation state after the last epoch, as the backend left it on
+    # its devices (single-federation runs of the scan engine)
+    final_state: Any = field(default=None, repr=False, compare=False)
 
     def final_accuracy(self) -> float:
         return self.avg_accuracy[-1] if self.avg_accuracy else float("nan")
@@ -571,6 +577,7 @@ def _append_window(result: SimulationResult, traj, mask: np.ndarray, start: int,
     # full per-epoch traces (no eval mask): diversity + communication volume
     result.kl_trace.extend(float(v) for v in np.asarray(traj["kl_mean"]))
     result.comm_mb.extend(float(v) for v in np.asarray(traj["comm_mb"]))
+    result.loss_trace.extend(float(v) for v in np.asarray(traj["loss"]))
     for i in np.nonzero(mask)[0]:
         accs = acc[i, :num_vehicles]
         result.epochs_evaluated.append(start + int(i) + 1)

@@ -63,6 +63,7 @@ def run_legacy_loop(ctx: EngineContext, progress: bool = False) -> SimulationRes
         state, diags = round_fn(state, contacts, ctx.target, batch, kr,
                                 ctx.fed_data)
         result.kl_trace.append(float(np.mean(np.asarray(diags["kl_divergence"]))))
+        result.loss_trace.append(float(np.mean(np.asarray(diags["loss"]))))
         result.comm_mb.append(
             float(np.asarray(contacts_lib.count_edges(contacts))) * payload_mb)
         if (epoch + 1) % cfg.eval_every == 0 or epoch == cfg.epochs - 1:

@@ -71,16 +71,16 @@ def gossip_mix_matmul(mixing: Array, flat: Array, *, interpret: bool = False,
 
 
 def _gather_mix_kernel(idx_ref, w_ref, x_ref, o_ref, *, k_out: int, d: int):
-    # idx_ref/w_ref: [K_out, D] scalar-prefetched (SMEM); x_ref/o_ref:
-    # [K_in_pad, BLOCK_P] / [K_out_pad, BLOCK_P] VMEM tiles. One output row
-    # at a time: D scalar-indexed row loads (pl.ds with a dynamic start)
+    # idx_ref/w_ref: [K_out * D] row-major, scalar-prefetched (SMEM); x_ref/
+    # o_ref: [K_in_pad, BLOCK_P] / [K_out_pad, BLOCK_P] VMEM tiles. One output
+    # row at a time: D scalar-indexed row loads (pl.ds with a dynamic start)
     # accumulated in f32 — the slot weights are tiny scalars, the row loads
     # stream from the resident X tile.
     def row(k, _):
         acc = jnp.zeros((1, o_ref.shape[-1]), jnp.float32)
         for slot in range(d):  # D_max is small and static: unrolled
-            i = idx_ref[k, slot]
-            wv = w_ref[k, slot].astype(jnp.float32)
+            i = idx_ref[k * d + slot]
+            wv = w_ref[k * d + slot].astype(jnp.float32)
             acc = acc + wv * x_ref[pl.ds(i, 1), :].astype(jnp.float32)
         o_ref[pl.ds(k, 1), :] = acc.astype(o_ref.dtype)
         return 0
@@ -100,6 +100,9 @@ def gossip_mix_gather(idx: Array, w: Array, flat: Array, *,
     the D_max contacted rows are touched per output row — O(K * D_max * P)
     flops against the dense kernel's O(K^2 * P). The neighbour ids ride the
     scalar-prefetch lane (SMEM) so row loads can be dynamically indexed.
+    They go in flattened to 1-D: SMEM pads a 2-D array's minor dimension to
+    128 lanes, so [K, D] ids and weights would take 2 * K * 128 * 4 bytes
+    and fill its 1 MiB at K = 1024.
     """
     k_in, p = flat.shape
     k_out, d = idx.shape
@@ -109,9 +112,10 @@ def gossip_mix_gather(idx: Array, w: Array, flat: Array, *,
     p_pad = _pad_to(max(p, LANE), block_p)
 
     # padded output rows gather row 0 with weight 0
-    idx_pad = jnp.zeros((k_out_pad, d), jnp.int32).at[:k_out].set(idx)
+    idx_pad = jnp.zeros((k_out_pad, d), jnp.int32).at[:k_out].set(
+        idx).reshape(-1)
     w_pad = jnp.zeros((k_out_pad, d), jnp.float32).at[:k_out].set(
-        w.astype(jnp.float32))
+        w.astype(jnp.float32)).reshape(-1)
     x = jnp.zeros((k_in_pad, p_pad), flat.dtype).at[:k_in, :p].set(flat)
 
     from jax.experimental.pallas import tpu as pltpu

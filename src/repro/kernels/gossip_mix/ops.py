@@ -1,45 +1,37 @@
-"""Jit'd public wrapper: apply the gossip mix to a parameter pytree using the
-Pallas kernels (TPU) or the jnp references (CPU / non-TPU backends).
+"""Jit'd public wrapper: apply the gossip mix to a parameter pytree through
+the Pallas kernels.
 
 Both mixing representations route through here behind the
 ``SimulationConfig.mixing_backend = "pallas"`` knob: a dense ``[K_out,
 K_in]`` matrix hits the blocked matmul kernel, a ``core.contacts
-.SparseMixing`` neighbour list hits the scalar-prefetch gather kernel."""
+.SparseMixing`` neighbour list hits the scalar-prefetch gather kernel. The
+kernel always runs: compiled on a TPU, in interpret mode elsewhere. The jnp
+references in ``ref.py`` are what tests compare it against, never a
+substitute for it."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
-from ...core.contacts import SparseMixing, sparse_mix_array
+from ...core.contacts import SparseMixing
 from .kernel import gossip_mix_gather, gossip_mix_matmul
-from .ref import gossip_mix_matmul_ref
 
 
-def _use_kernel(interpret: bool) -> bool:
-    return interpret or jax.default_backend() == "tpu"
-
-
-def mix_params_pallas(mixing, params, *, interpret: bool = False):
+def mix_params_pallas(mixing, params):
     """Drop-in replacement for repro.core.aggregation.mix_params.
 
     Flattens every leaf to [K_in, -1], runs the blocked kernel, reshapes
     back. ``mixing`` may be rectangular [K_out, K_in] — the per-shard
     partial-matmul block of the shard_map backend — or a ``SparseMixing``
     whose ids address the leaf rows (possibly shard-remapped), in which case
-    the gather kernel runs. Falls back to the jnp oracle (dense) or the
-    slot-scan ``sparse_mix_array`` (sparse) off-TPU unless ``interpret``.
+    the gather kernel runs. Off the TPU the kernel runs in interpret mode.
     """
+    interpret = jax.default_backend() != "tpu"
     if isinstance(mixing, SparseMixing):
-        if not _use_kernel(interpret):
-            return jax.tree_util.tree_map(
-                lambda x: sparse_mix_array(mixing, x), params)
         run = lambda x: gossip_mix_gather(mixing.idx, mixing.w, x,
                                           interpret=interpret)
         k_out = mixing.idx.shape[0]
     else:
-        run = ((lambda w, x: gossip_mix_matmul(w, x, interpret=interpret))
-               if _use_kernel(interpret) else gossip_mix_matmul_ref)
-        run = lambda x, _run=run: _run(mixing, x)
+        run = lambda x: gossip_mix_matmul(mixing, x, interpret=interpret)
         k_out = mixing.shape[0]
 
     def mix_leaf(x: jax.Array) -> jax.Array:

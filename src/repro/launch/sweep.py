@@ -24,6 +24,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..data import datasets as data_lib
 from ..fed import backends as backends_lib
 from ..fed import engine
@@ -139,6 +140,7 @@ def main(argv: Sequence[str] | None = None) -> list[str]:
                     help="auto picks backend/contact_format/d_max from the "
                          "analytical cost model (roofline.scenario_cost)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     base = SimulationConfig(
         dataset=args.dataset, num_vehicles=args.vehicles, epochs=args.epochs,

@@ -9,6 +9,9 @@ sanity ranges (rates positive, ratios positive, device/fleet counts >= 1).
 ``benchmarks/engine_backends.py``, ``benchmarks/engine_scale.py`` and
 ``benchmarks/collective_sweep.py`` produce the files;
 tests/test_bench_schema.py holds the committed copies to this schema.
+
+Every report names the devices it was measured on (``DEVICE_KEYS``, filled
+by ``device_fields``), so a CPU timing can never pass for a chip's.
 """
 from __future__ import annotations
 
@@ -16,6 +19,18 @@ import json
 from typing import Any
 
 _NUMBER = (int, float)
+
+# top-level keys naming the devices a report was measured on
+DEVICE_KEYS = ("platform", "device_kind", "device_count")
+
+
+def device_fields() -> dict:
+    """``DEVICE_KEYS`` for the devices this process's JAX runs on."""
+    import jax
+
+    device = jax.devices()[0]
+    return {"platform": device.platform, "device_kind": device.device_kind,
+            "device_count": jax.device_count()}
 
 # required result-row keys -> (type, validator) — names carry the units
 # (epochs_per_s, peak_rss_mb, contact_window_mb)
@@ -71,9 +86,15 @@ def _check_report(report: Any, benchmark: str, row_schema: dict,
                   extra_top: tuple[str, ...] = ()) -> dict:
     if not isinstance(report, dict):
         raise BenchSchemaError(f"{benchmark}: report is not an object")
-    for key in ("benchmark", "workload", "results") + extra_top:
+    for key in ("benchmark", "workload", "results") + DEVICE_KEYS + extra_top:
         if key not in report:
             raise BenchSchemaError(f"{benchmark}: missing top-level {key!r}")
+    for key in ("platform", "device_kind"):
+        if not isinstance(report[key], str) or not report[key]:
+            raise BenchSchemaError(f"{benchmark}: {key}={report[key]!r}")
+    dc = report["device_count"]
+    if isinstance(dc, bool) or not isinstance(dc, int) or dc < 1:
+        raise BenchSchemaError(f"{benchmark}: device_count={dc!r}")
     if report["benchmark"] != benchmark:
         raise BenchSchemaError(
             f"expected benchmark={benchmark!r}, got {report['benchmark']!r}")
@@ -86,11 +107,7 @@ def _check_report(report: Any, benchmark: str, row_schema: dict,
 
 def validate_engine_report(report: Any) -> dict:
     """Validate a BENCH_engine.json report (vmap vs shard_map pairs)."""
-    _check_report(report, "engine_backends", ENGINE_ROW_SCHEMA,
-                  extra_top=("device_count",))
-    dc = report["device_count"]
-    if not isinstance(dc, int) or dc < 1:
-        raise BenchSchemaError(f"engine_backends: device_count={dc!r}")
+    _check_report(report, "engine_backends", ENGINE_ROW_SCHEMA)
     for i, r in enumerate(report["results"]):
         measured = r["shard_map_epochs_per_s"] / r["vmap_epochs_per_s"]
         if abs(measured - r["shard_vs_vmap"]) > 0.01 * max(measured, 1.0):
@@ -127,11 +144,10 @@ def validate_collective_report(report: Any) -> dict:
     overlap-aware collective term is calibrated from
     (scenario_cost.profile_from_collective_bench)."""
     _check_report(report, "collective_sweep", COLLECTIVE_ROW_SCHEMA,
-                  extra_top=("device_count", "axis_size", "derived"))
-    for key in ("device_count", "axis_size"):
-        v = report[key]
-        if isinstance(v, bool) or not isinstance(v, int) or v < 1:
-            raise BenchSchemaError(f"collective_sweep: {key}={v!r}")
+                  extra_top=("axis_size", "derived"))
+    v = report["axis_size"]
+    if isinstance(v, bool) or not isinstance(v, int) or v < 1:
+        raise BenchSchemaError(f"collective_sweep: axis_size={v!r}")
     derived = report["derived"]
     if not isinstance(derived, dict):
         raise BenchSchemaError("collective_sweep: derived is not an object")

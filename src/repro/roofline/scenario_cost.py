@@ -107,10 +107,25 @@ TPU_V5E = HostProfile(
 )
 
 
+# accelerator profiles by ``jax.Device.device_kind``
+ACCELERATOR_PROFILES = {"TPU v5 lite": TPU_V5E}
+
+
 def default_host_profile() -> HostProfile:
+    """The profile of the device JAX runs on: ``CI_HOST`` for the CPU, else
+    the entry for its ``device_kind``. An accelerator with no entry is an
+    error, never a guess."""
     import jax
 
-    return TPU_V5E if jax.default_backend() == "tpu" else CI_HOST
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return CI_HOST
+    try:
+        return ACCELERATOR_PROFILES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model profile for device kind {device.device_kind!r} "
+            f"(known: {sorted(ACCELERATOR_PROFILES)})") from None
 
 
 def profile_from_collective_bench(report: dict,

@@ -178,6 +178,9 @@ def test_every_algorithm_parity_across_backends(tiny_ds, algorithm,
         np.testing.assert_allclose(res.consensus_distance,
                                    legacy.consensus_distance, rtol=1e-4,
                                    atol=1e-5)
+        np.testing.assert_allclose(res.loss_trace, legacy.loss_trace,
+                                   rtol=1e-5)
+        assert len(res.loss_trace) == cfg.epochs
 
 
 @pytest.mark.parametrize("algorithm", algorithms.available_algorithms())
@@ -288,6 +291,10 @@ vmap_res = run_simulation(cfg, dataset=ds)
 shard_res = run_simulation(replace(cfg, backend="shard_map"), dataset=ds)
 np.testing.assert_allclose(shard_res.avg_accuracy, vmap_res.avg_accuracy, atol=1e-5)
 np.testing.assert_allclose(shard_res.vehicle_accuracy, vmap_res.vehicle_accuracy, atol=1e-5)
+# the final state lives on all four devices, the model rows split over them
+leaves = jax.tree_util.tree_leaves(shard_res.final_state)
+assert all(len(l.sharding.device_set) == 4 for l in leaves)
+assert any(not l.sharding.is_fully_replicated for l in leaves)
 print("SHARD_PARITY_OK")
 """
     env = dict(os.environ,

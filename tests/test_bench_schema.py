@@ -189,3 +189,17 @@ def test_reports_are_plain_json(engine_report, scale_report,
     json.dumps(engine_report)
     json.dumps(scale_report)
     json.dumps(collective_report)
+
+
+@pytest.mark.parametrize("key", ["platform", "device_kind", "device_count"])
+def test_report_without_its_device_rejected(engine_report, scale_report,
+                                            collective_report, key):
+    """Every report names the devices it ran on: a timing without them
+    could pass for a chip's."""
+    for report, validate in ((engine_report, validate_engine_report),
+                             (scale_report, validate_scale_report),
+                             (collective_report, validate_collective_report)):
+        bad = copy.deepcopy(report)
+        del bad[key]
+        with pytest.raises(BenchSchemaError, match=key):
+            validate(bad)

@@ -48,7 +48,6 @@ _CHILD = textwrap.dedent("""
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import aggregation, vehicle_axis
 
     assert jax.process_count() == 2
@@ -76,9 +75,9 @@ _CHILD = textwrap.dedent("""
     def body(w, x):
         return mix(w, {"a": x, "b": 2.0 * x})["a"]
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P("vehicle")),
-        out_specs=P("vehicle"), check_rep=False))(W, X)
+        out_specs=P("vehicle"), check_vma=False))(W, X)
 
     ref = W_np @ X_np                    # the cross-host gossip contraction
     for s in out.addressable_shards:

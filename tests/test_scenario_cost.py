@@ -218,3 +218,28 @@ def test_predicted_vs_measured_table_renders(engine_report, scale_report):
         scenario_cost.replay_bench_scale(scale_report))
     assert "MISMATCH" not in table
     assert "sparse-vs-dense K=1024" in table
+
+
+# ------------------------------------------------------ profile by device ----
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,expected", [
+    ("cpu", "cpu", scenario_cost.CI_HOST),
+    ("tpu", "TPU v5 lite", scenario_cost.TPU_V5E),
+])
+def test_host_profile_keyed_by_device_kind(monkeypatch, platform, kind,
+                                           expected):
+    monkeypatch.setattr("jax.devices", lambda: [_FakeDevice(platform, kind)])
+    assert scenario_cost.default_host_profile() is expected
+
+
+def test_unknown_accelerator_kind_raises(monkeypatch):
+    """Every TPU used to get the v5e profile; an unknown kind is an error."""
+    monkeypatch.setattr("jax.devices",
+                        lambda: [_FakeDevice("tpu", "TPU v4")])
+    with pytest.raises(ValueError, match="TPU v4"):
+        scenario_cost.default_host_profile()
