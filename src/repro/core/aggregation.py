@@ -35,6 +35,7 @@ def _renormalize(idx: Array, w: Array) -> SparseMixing:
         idx, w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-12))
 
 
+@jax.named_scope("p1_solve")
 def mixing_from_alpha(alpha: Array, contacts) -> Array | SparseMixing:
     """Mask + renormalize alpha rows onto the contact set -> row-stochastic W.
 
@@ -84,6 +85,7 @@ def sample_size_mixing(contacts, sample_counts: Array) -> Array | SparseMixing:
     return w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-12)
 
 
+@jax.named_scope("gossip_mix")
 def mix_params(mixing, params):
     """Apply the gossip mix to a pytree with leading vehicle axis K.
 
@@ -113,6 +115,7 @@ def mix_params(mixing, params):
     return jax.tree_util.tree_map(mix_leaf, params)
 
 
+@jax.named_scope("gossip_mix")
 def mix_params_lowp(mixing: Array, params):
     """Gossip mix with a bfloat16 exchange payload (beyond-paper perf
     variant): the cross-vehicle all-gather moves bf16, accumulation stays

@@ -95,6 +95,7 @@ def solve_p1(
 
 
 @partial(jax.jit, static_argnames=("num_steps",))
+@jax.named_scope("p1_solve")
 def solve_p1_all(
     states: Array,
     target: Array,

@@ -26,6 +26,7 @@ def init_state(num_vehicles: int, dtype=jnp.float32) -> Array:
     return jnp.zeros((num_vehicles, num_vehicles), dtype=dtype)
 
 
+@jax.named_scope("state_vector")
 def local_update(state: Array, lr: float | Array, local_steps: int | Array,
                  update_mask: Array | None = None) -> Array:
     """Eq. (5) applied ``local_steps`` times followed by Eq. (6).
@@ -52,6 +53,7 @@ def normalize(state: Array, eps: float = 1e-12) -> Array:
     return jnp.where(tot > eps, state / jnp.maximum(tot, eps), state)
 
 
+@jax.named_scope("state_vector")
 def aggregate(state: Array, mixing) -> Array:
     """Eq. (7) for all vehicles at once: ``S' = W @ S``.
 
@@ -66,6 +68,7 @@ def aggregate(state: Array, mixing) -> Array:
     return mixing @ state
 
 
+@jax.named_scope("state_vector")
 def entropy(state: Array, eps: float = 1e-12) -> Array:
     """Eq. (8): per-vehicle entropy H(s_k) in bits. ``state`` rows must be on
     the simplex. Returns ``[K]``."""
@@ -74,6 +77,7 @@ def entropy(state: Array, eps: float = 1e-12) -> Array:
     return h
 
 
+@jax.named_scope("state_vector")
 def kl_to_target(state: Array, target: Array, eps: float = 1e-12) -> Array:
     """Eq. (9): per-vehicle D_KL(s_k || g) in bits. Returns ``[K]``.
 

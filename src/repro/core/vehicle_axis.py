@@ -172,6 +172,7 @@ def sharded_mix(base_mix_fn: MixParamsFn, shard: VehicleSharding,
         return jax.lax.psum_scatter(t, shard.axis_name, scatter_dimension=0,
                                     tiled=True)
 
+    @jax.named_scope("gossip_mix")
     def mix(mixing, params: PyTree) -> PyTree:
         leaves, treedef = jax.tree_util.tree_flatten(params)
         mixing = local_mixing(mixing, leaves[0].shape[0])
@@ -235,6 +236,7 @@ def delayed_gossip_mix(mix_fn: MixParamsFn, shard: VehicleSharding) -> Callable:
     the degenerate trajectory is bit-identical to synchronous gossip — the
     parity anchor tests/test_backends.py holds it to."""
 
+    @jax.named_scope("gossip_mix")
     def mix(mixing, params: PyTree, stale: PyTree) -> PyTree:
         neighbours = mix_fn(zero_self_weight(mixing), stale)
         self_w = shard.local_rows(mixing_self_weight(mixing))

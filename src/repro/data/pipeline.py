@@ -40,6 +40,7 @@ def sample_batches(data: FederatedData, rng: Array, local_steps: int, batch_size
     return sample_batches_sliced(data, rng, local_steps, batch_size)
 
 
+@jax.named_scope("sample_batches")
 def sample_batches_sliced(data: FederatedData, rng: Array, local_steps: int,
                           batch_size: int, take_rows=None):
     """``sample_batches`` with an optional vehicle-row slice.
@@ -107,6 +108,7 @@ def sample_full_batches(data: FederatedData, rng: Array, batch_size: int):
     return sample_full_batches_sliced(data, rng, batch_size)
 
 
+@jax.named_scope("sample_batches")
 def sample_full_batches_sliced(data: FederatedData, rng: Array,
                                batch_size: int, take_rows=None):
     """``sample_full_batches`` with an optional vehicle-row slice (see

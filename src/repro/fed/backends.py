@@ -20,7 +20,6 @@ pick them up by name with no engine edits.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import fields, replace
 from typing import Any
 
@@ -29,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from .. import telemetry
 from ..core import contacts as contacts_lib
 from ..core.vehicle_axis import VehicleSharding
 from ..data import datasets as data_lib
@@ -82,21 +82,23 @@ def _drive_windows(ctx, window_fn, progress: bool):
     masked trajectory rows. Both backends differ only in what ``window_fn``
     is."""
     cfg = ctx.cfg
-    t0 = time.time()
     result = engine_lib.SimulationResult(config=cfg,
                                          execution_plan=ctx.execution_plan)
     window_size = engine_lib._default_window(cfg, progress)
     state, rng = ctx.init_state, ctx.init_rng
-    for start in range(0, cfg.epochs, window_size):
-        length = min(window_size, cfg.epochs - start)
-        contacts = jax.tree_util.tree_map(jnp.asarray,
-                                          ctx.contacts.window(length))
-        mask = engine_lib._eval_mask(cfg, start, length)
-        state, rng, traj = window_fn(
-            state, rng, ctx.fed_data, ctx.target, contacts, jnp.asarray(mask))
-        engine_lib._append_window(result, traj, mask, start, cfg.num_vehicles,
-                                  progress)
-    result.wall_time = time.time() - t0
+    with telemetry.span("fed.federation", federation=True) as federation:
+        for start in range(0, cfg.epochs, window_size):
+            length = min(window_size, cfg.epochs - start)
+            window = ctx.contacts.window(length)
+            mask = engine_lib._eval_mask(cfg, start, length)
+            with telemetry.span("fed.dispatch"):
+                contacts = jax.tree_util.tree_map(jnp.asarray, window)
+                state, rng, traj = window_fn(state, rng, ctx.fed_data,
+                                             ctx.target, contacts,
+                                             jnp.asarray(mask))
+            engine_lib._append_window(result, traj, mask, start,
+                                      cfg.num_vehicles, progress)
+    result.wall_time = federation.seconds
     result.final_state = state
     return result
 

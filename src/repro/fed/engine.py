@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..core import aggregation, state_vector, vehicle_axis
 from ..core import contacts as contacts_lib
 from ..data import datasets as data_lib
@@ -198,6 +199,7 @@ def exchange_payload_mb(ctx: "EngineContext") -> float:
 def make_local_train_fn(loss_fn, optimizer):
     """Per-vehicle E local SGD steps via lax.scan (Eq. 3)."""
 
+    @jax.named_scope("local_train")
     def local_train(params, opt_state, batch, rng):
         xs, ys = batch  # [E, B, ...], [E, B]
         steps = xs.shape[0]
@@ -302,15 +304,22 @@ class ContactStream:
         return probe_d_max(self.cfg, net)
 
     def window(self, num_epochs: int):
-        positions = self.mob.advance_positions(num_epochs)
-        if self.format.sparse:
-            idx, mask = extensions_lib.neighbour_window(
+        """The next ``num_epochs`` epochs of contacts, as a ``fed.contacts``
+        span. A sparse window also counts its neighbour slots
+        (``fed.contact_slots``, T x K x D_max) and the slots that hold a
+        contact (``fed.contact_edges``, self slots included)."""
+        with telemetry.span("fed.contacts"):
+            positions = self.mob.advance_positions(num_epochs)
+            if self.format.sparse:
+                idx, mask = extensions_lib.neighbour_window(
+                    positions, self.rsu_pos, self.cfg.comm_range,
+                    self.cfg.p_drop, self.drop_rng, self.d_max)
+                telemetry.count("fed.contact_slots", mask.size)
+                telemetry.count("fed.contact_edges", int(mask.sum()))
+                return contacts_lib.SparseContacts(idx, mask)
+            return extensions_lib.contact_window(
                 positions, self.rsu_pos, self.cfg.comm_range, self.cfg.p_drop,
-                self.drop_rng, self.d_max)
-            return contacts_lib.SparseContacts(idx, mask)
-        return extensions_lib.contact_window(
-            positions, self.rsu_pos, self.cfg.comm_range, self.cfg.p_drop,
-            self.drop_rng)
+                self.drop_rng)
 
 
 @dataclass
@@ -401,7 +410,13 @@ def build_context(cfg: SimulationConfig, dataset=None) -> EngineContext:
     themselves and are addressable by ``cfg.algorithm`` immediately.
 
     ``execution="auto"`` configs are resolved here (cost-model backend /
-    format selection); the resulting plan rides on ``ctx.execution_plan``."""
+    format selection); the resulting plan rides on ``ctx.execution_plan``.
+    The whole build is one ``fed.build_context`` span."""
+    with telemetry.span("fed.build_context"):
+        return _build_context(cfg, dataset)
+
+
+def _build_context(cfg: SimulationConfig, dataset) -> EngineContext:
     cfg, execution_plan = resolve_execution(cfg)
     ds = dataset or data_lib.load_dataset(cfg.dataset, seed=cfg.seed)
     init_fn, loss_fn, accuracy_fn = cnn_lib.make_cnn_task(ds.name)
@@ -508,6 +523,7 @@ def build_window_fn(ctx: EngineContext) -> Callable:
         return (algo_st, sent.get("payload", stale)), diags
 
     def window(state, rng, fed_data, target, contacts, eval_mask):
+        @jax.named_scope("eval")
         def evaluate(st):
             model = model_of(st)
             consensus = aggregation.consensus_distance(
@@ -570,25 +586,29 @@ def _eval_mask(cfg: SimulationConfig, start: int, length: int) -> np.ndarray:
 
 def _append_window(result: SimulationResult, traj, mask: np.ndarray, start: int,
                    num_vehicles: int, progress: bool) -> None:
-    acc = np.asarray(traj["accuracy"])
-    ent = np.asarray(traj["entropy"])
-    kl = np.asarray(traj["kl_divergence"])
-    consensus = np.asarray(traj["consensus"])
-    # full per-epoch traces (no eval mask): diversity + communication volume
-    result.kl_trace.extend(float(v) for v in np.asarray(traj["kl_mean"]))
-    result.comm_mb.extend(float(v) for v in np.asarray(traj["comm_mb"]))
-    result.loss_trace.extend(float(v) for v in np.asarray(traj["loss"]))
-    for i in np.nonzero(mask)[0]:
-        accs = acc[i, :num_vehicles]
-        result.epochs_evaluated.append(start + int(i) + 1)
-        result.avg_accuracy.append(float(accs.mean()))
-        result.vehicle_accuracy.append(accs)
-        result.entropy.append(ent[i])
-        result.kl_divergence.append(kl[i])
-        result.consensus_distance.append(float(consensus[i]))
-        if progress:
-            print(f"  epoch {start + int(i) + 1:4d}  avg_acc={accs.mean():.4f}  "
-                  f"min={accs.min():.4f}  max={accs.max():.4f}", flush=True)
+    """Copy a window's trajectory back to the host (waiting for the device
+    to finish it) and append it to ``result``, as a ``fed.collect`` span."""
+    with telemetry.span("fed.collect"):
+        acc = np.asarray(traj["accuracy"])
+        ent = np.asarray(traj["entropy"])
+        kl = np.asarray(traj["kl_divergence"])
+        consensus = np.asarray(traj["consensus"])
+        # full per-epoch traces (no eval mask): diversity + communication volume
+        result.kl_trace.extend(float(v) for v in np.asarray(traj["kl_mean"]))
+        result.comm_mb.extend(float(v) for v in np.asarray(traj["comm_mb"]))
+        result.loss_trace.extend(float(v) for v in np.asarray(traj["loss"]))
+        for i in np.nonzero(mask)[0]:
+            accs = acc[i, :num_vehicles]
+            result.epochs_evaluated.append(start + int(i) + 1)
+            result.avg_accuracy.append(float(accs.mean()))
+            result.vehicle_accuracy.append(accs)
+            result.entropy.append(ent[i])
+            result.kl_divergence.append(kl[i])
+            result.consensus_distance.append(float(consensus[i]))
+            if progress:
+                print(f"  epoch {start + int(i) + 1:4d}  "
+                      f"avg_acc={accs.mean():.4f}  min={accs.min():.4f}  "
+                      f"max={accs.max():.4f}", flush=True)
 
 
 def run_with_context(ctx: EngineContext, progress: bool = False) -> SimulationResult:

@@ -16,6 +16,7 @@ from ...core.contacts import SparseMixing
 from .kernel import gossip_mix_gather, gossip_mix_matmul
 
 
+@jax.named_scope("gossip_mix")
 def mix_params_pallas(mixing, params):
     """Drop-in replacement for repro.core.aggregation.mix_params.
 

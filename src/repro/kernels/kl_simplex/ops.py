@@ -32,6 +32,7 @@ def entropy_rows(states, *, interpret: bool = False):
 
 
 @partial(jax.jit, static_argnames=("num_steps", "step_size", "interpret"))
+@jax.named_scope("p1_solve")
 def solve_p1_all_fused(states, target, contact_matrix, *, num_steps: int = 400,
                        step_size: float = 2.0, interpret: bool = False):
     """Kernel-backed drop-in for repro.core.kl_solver.solve_p1_all."""
