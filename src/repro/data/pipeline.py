@@ -4,6 +4,15 @@ Everything stays on-device: the full train set lives as a device array; each
 global epoch the pipeline draws per-vehicle (E local steps x B) sample
 indices from the vehicle's partition (dense [K, W] index table with true
 counts, see partition.pad_to_uniform) and gathers inside jit.
+
+``FederatedData.x`` is stored flat, one row of ``H*W*C`` values a sample:
+the gather copies whole rows and only the small batch is reshaped to
+``[..., H, W, C]``, because a 4-D image array laid out sample-minor (what a
+conv over a 1- or 3-wide channel axis asks for) makes the gather copy single
+lanes. A TPU's default layout for the flat ``[N, H*W*C]`` set also puts N
+minor (it pads least); the compiler then relays it out row-major once a
+program, outside the epoch loop, and gathers rows from that. The samplers
+take the sample shape ``(H, W, C)`` as a static argument to restore it.
 """
 from __future__ import annotations
 
@@ -18,7 +27,7 @@ Array = jax.Array
 
 
 class FederatedData(NamedTuple):
-    x: Array            # [N, ...] full train inputs (device)
+    x: Array            # [N, H*W*C] full train inputs, one row a sample (device)
     y: Array            # [N] labels
     index_table: Array  # [K, W] per-vehicle sample indices (padded, resampled)
     counts: Array       # [K] true per-vehicle sample counts
@@ -27,22 +36,31 @@ class FederatedData(NamedTuple):
 def make_federated_data(train_x: np.ndarray, train_y: np.ndarray,
                         dense_indices: np.ndarray, counts: np.ndarray) -> FederatedData:
     return FederatedData(
-        x=jnp.asarray(train_x),
+        x=jnp.asarray(train_x.reshape(len(train_x), -1)),
         y=jnp.asarray(train_y),
         index_table=jnp.asarray(dense_indices),
         counts=jnp.asarray(counts),
     )
 
 
-@partial(jax.jit, static_argnames=("local_steps", "batch_size"))
-def sample_batches(data: FederatedData, rng: Array, local_steps: int, batch_size: int):
-    """Draw per-vehicle minibatches: returns (x, y) of shape [K, E, B, ...]."""
-    return sample_batches_sliced(data, rng, local_steps, batch_size)
+def _gather(data: FederatedData, idx: Array, sample_shape: tuple[int, ...]):
+    """Rows ``idx`` of the train set, each reshaped to ``sample_shape``."""
+    return data.x[idx].reshape(idx.shape + tuple(sample_shape)), data.y[idx]
+
+
+@partial(jax.jit, static_argnames=("local_steps", "batch_size", "sample_shape"))
+def sample_batches(data: FederatedData, rng: Array, local_steps: int,
+                   batch_size: int, sample_shape: tuple[int, ...]):
+    """Draw per-vehicle minibatches: returns (x, y) of shape
+    [K, E, B, *sample_shape] and [K, E, B]."""
+    return sample_batches_sliced(data, rng, local_steps, batch_size,
+                                 sample_shape)
 
 
 @jax.named_scope("sample_batches")
 def sample_batches_sliced(data: FederatedData, rng: Array, local_steps: int,
-                          batch_size: int, take_rows=None):
+                          batch_size: int, sample_shape: tuple[int, ...],
+                          take_rows=None):
     """``sample_batches`` with an optional vehicle-row slice.
 
     ``take_rows`` maps a [K, ...] array to the caller's rows — identity (None)
@@ -58,7 +76,7 @@ def sample_batches_sliced(data: FederatedData, rng: Array, local_steps: int,
         picks, table = take_rows(picks), take_rows(table)
     rows = jnp.arange(table.shape[0])
     idx = table[rows[:, None, None], picks]  # [K_rows, E, B]
-    return data.x[idx], data.y[idx]
+    return _gather(data, idx, sample_shape)
 
 
 def stack_federated_data(datas: list[FederatedData], seed: int = 0) -> FederatedData:
@@ -99,18 +117,20 @@ def stack_federated_data(datas: list[FederatedData], seed: int = 0) -> Federated
     )
 
 
-@partial(jax.jit, static_argnames=("batch_size",))
-def sample_full_batches(data: FederatedData, rng: Array, batch_size: int):
+@partial(jax.jit, static_argnames=("batch_size", "sample_shape"))
+def sample_full_batches(data: FederatedData, rng: Array, batch_size: int,
+                        sample_shape: tuple[int, ...]):
     """One batch per vehicle of ``batch_size`` samples drawn from its
     partition — used by SP's single full-set local iteration (the paper's SP
     uses all local samples; we draw ``batch_size`` >= typical partition size,
     with self-resampling padding preserving the distribution)."""
-    return sample_full_batches_sliced(data, rng, batch_size)
+    return sample_full_batches_sliced(data, rng, batch_size, sample_shape)
 
 
 @jax.named_scope("sample_batches")
 def sample_full_batches_sliced(data: FederatedData, rng: Array,
-                               batch_size: int, take_rows=None):
+                               batch_size: int, sample_shape: tuple[int, ...],
+                               take_rows=None):
     """``sample_full_batches`` with an optional vehicle-row slice (see
     ``sample_batches_sliced`` — full pick tensor first, slice after, so the
     random stream is backend-invariant)."""
@@ -120,4 +140,4 @@ def sample_full_batches_sliced(data: FederatedData, rng: Array,
     if take_rows is not None:
         picks, table = take_rows(picks), take_rows(table)
     idx = jnp.take_along_axis(table, picks, axis=-1)
-    return data.x[idx], data.y[idx]
+    return _gather(data, idx, sample_shape)

@@ -14,10 +14,10 @@ round, bundled behind four hooks (plus a sharding spec):
   matrices replicated).
 
 ``AlgorithmSetup`` carries the per-run context the engine builds once
-(``engine.build_context``): config, local-train fn, initial stacks, the
-resolved gossip-mix fn, and the vehicle-axis sharding regime. Execution
-backends rebind ``shard`` (and wrap ``mix_params_fn``) without the
-algorithm knowing which backend it runs under.
+(``engine.build_context``): config, one sample's shape, local-train fn,
+initial stacks, the resolved gossip-mix fn, and the vehicle-axis sharding
+regime. Execution backends rebind ``shard`` (and wrap ``mix_params_fn``)
+without the algorithm knowing which backend it runs under.
 
 Registering a new algorithm makes it addressable by name from
 ``SimulationConfig.algorithm`` and the sweep runner with zero engine edits:
@@ -51,6 +51,7 @@ class AlgorithmSetup:
     """
     cfg: Any                        # SimulationConfig (duck-typed; no engine import)
     total_nodes: int                # vehicles + RSUs
+    sample_shape: tuple[int, ...]   # one sample's (H, W, C); fed_data.x is flat
     loss_fn: Callable               # loss(params, x, y, rng) for one vehicle
     local_train_fn: Callable        # E local SGD steps for one vehicle
     params_stack: PyTree            # [K, ...] identical-init model stack
@@ -84,9 +85,9 @@ class Algorithm:
         if setup.shard.is_sharded:
             return pipeline.sample_batches_sliced(
                 fed_data, rng, cfg.local_steps, cfg.batch_size,
-                take_rows=setup.shard.local_rows)
+                setup.sample_shape, take_rows=setup.shard.local_rows)
         return pipeline.sample_batches(fed_data, rng, cfg.local_steps,
-                                       cfg.batch_size)
+                                       cfg.batch_size, setup.sample_shape)
 
     def model_of(self, setup: AlgorithmSetup, state: PyTree) -> PyTree:
         raise NotImplementedError

@@ -49,8 +49,10 @@ class SP(Algorithm):
         full_bs = min(int(fed_data.index_table.shape[-1]), FULL_BATCH_CAP)
         if setup.shard.is_sharded:
             return pipeline.sample_full_batches_sliced(
-                fed_data, rng, full_bs, take_rows=setup.shard.local_rows)
-        return pipeline.sample_full_batches(fed_data, rng, full_bs)
+                fed_data, rng, full_bs, setup.sample_shape,
+                take_rows=setup.shard.local_rows)
+        return pipeline.sample_full_batches(fed_data, rng, full_bs,
+                                            setup.sample_shape)
 
     def model_of(self, setup, state):
         return baselines.sp_model(state, shard=setup.shard)

@@ -455,7 +455,8 @@ def _build_context(cfg: SimulationConfig, dataset) -> EngineContext:
 
     algo = algorithms_lib.get_algorithm(cfg.algorithm)
     setup = algorithms_lib.AlgorithmSetup(
-        cfg=cfg, total_nodes=total_nodes, loss_fn=loss_fn,
+        cfg=cfg, total_nodes=total_nodes,
+        sample_shape=tuple(ds.train_x.shape[1:]), loss_fn=loss_fn,
         local_train_fn=local_train_fn, params_stack=params_stack,
         opt_stack=opt_stack, local_mask=local_mask,
         mix_params_fn=resolve_mix_params_fn(cfg))

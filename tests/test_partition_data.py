@@ -3,6 +3,7 @@ import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 
 from repro.data import pipeline, synthetic
@@ -74,9 +75,53 @@ def test_pipeline_batches_come_from_own_partition():
     parts = plib.balanced_noniid(ds.train_y, 4, seed=0)
     dense, counts = plib.pad_to_uniform(parts)
     fd = pipeline.make_federated_data(ds.train_x, ds.train_y, dense, counts)
-    xs, ys = pipeline.sample_batches(fd, jax.random.PRNGKey(0), 3, 8)
+    xs, ys = pipeline.sample_batches(fd, jax.random.PRNGKey(0), 3, 8,
+                                     ds.train_x.shape[1:])
     assert xs.shape == (4, 3, 8, 28, 28, 1)
     # every sampled label must exist in the vehicle's own partition
     for k in range(4):
         own = set(np.asarray(ds.train_y[parts[k]]))
         assert set(np.asarray(ys[k]).ravel()) <= own
+
+
+def _rows_1_to_3(a):
+    return a[1:3]
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["batches", "full"])
+@pytest.mark.parametrize("take_rows", [None, _rows_1_to_3],
+                         ids=["all_rows", "row_block"])
+@pytest.mark.parametrize("sample_shape", [(28, 28, 1), (32, 32, 3)],
+                         ids=["mnist", "cifar10"])
+def test_samplers_match_image_gather_bitwise(sample_shape, take_rows, full):
+    """The row gather from the flat [N, H*W*C] set gives, bit for bit and in
+    the same shape, the batches of a gather from the [N, H, W, C] images
+    with the same key, picks and vehicle rows."""
+    n, k, w, e, b = 200, 4, 30, 3, 8
+    rng = np.random.default_rng(0)
+    x4d = rng.standard_normal((n,) + sample_shape).astype(np.float32)
+    y = rng.integers(0, 10, n).astype(np.int32)
+    table = rng.integers(0, n, (k, w)).astype(np.int32)
+    fd = pipeline.make_federated_data(x4d, y, table, np.full(k, w))
+    assert fd.x.shape == (n, int(np.prod(sample_shape)))
+    key = jax.random.PRNGKey(7)
+
+    picks_shape = (k, b) if full else (k, e, b)
+    picks = jax.random.randint(key, picks_shape, 0, w)
+    ref_table = jnp.asarray(table)
+    if take_rows is not None:
+        picks, ref_table = take_rows(picks), take_rows(ref_table)
+    if full:
+        idx = jnp.take_along_axis(ref_table, picks, axis=-1)
+        xs, ys = pipeline.sample_full_batches_sliced(
+            fd, key, b, sample_shape, take_rows=take_rows)
+    else:
+        rows = jnp.arange(ref_table.shape[0])
+        idx = ref_table[rows[:, None, None], picks]
+        xs, ys = pipeline.sample_batches_sliced(
+            fd, key, e, b, sample_shape, take_rows=take_rows)
+    want_x, want_y = jnp.asarray(x4d)[idx], jnp.asarray(y)[idx]
+    assert xs.shape == want_x.shape == idx.shape + sample_shape
+    assert xs.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(xs), np.asarray(want_x))
+    np.testing.assert_array_equal(np.asarray(ys), np.asarray(want_y))
