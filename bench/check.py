@@ -10,11 +10,12 @@ the plain reference (``bench.reference.federation``) of the same seed:
   the models, so it stays close for the whole federation);
 - ``acc_gap``: the mean eval accuracy at the last evaluated epoch, as an
   absolute difference;
-- ``change_gap``: for the last federation, per parameter leaf, the gap
-  between the norm of the program's change over the federation and the
-  reference's, as a share of the larger of that leaf's reference norm and
-  the median leaf's; the worst leaf counts. Leaves whose reference change is
-  under a thousandth of the median leaf's are left out.
+- ``change_gap``: for the last federation, per parameter leaf (keyed by
+  its path in the parameter tree), the gap between the norm of the
+  program's change over the federation and the reference's, as a share of
+  the larger of that leaf's reference norm and the median leaf's; the worst
+  leaf counts. Leaves whose reference change is under a thousandth of the
+  median leaf's are left out.
 
 The limit of each number is in ``bench/limits/<workload>.json``; a number
 without a limit there is not compared.
@@ -24,17 +25,25 @@ from __future__ import annotations
 import gc
 
 import numpy as np
+from jax import tree_util
 
 LOSS_EPOCHS = 3
 QUIET_LEAF = 1e-3
 
 
 def change_norms(last, first) -> dict[str, float]:
-    """Per leaf of a stacked parameter dict, ||last - first|| over the whole
-    stack, in float32 on the host."""
-    return {k: float(np.linalg.norm(np.asarray(last[k], np.float32)
-                                    - np.asarray(first[k], np.float32)))
-            for k in last}
+    """Per leaf of a stacked parameter tree, keyed by its path
+    (``jax.tree_util.keystr``), ||last - first|| over the whole stack, in
+    float32 on the host."""
+    firsts = dict(_leaves(first))
+    return {path: float(np.linalg.norm(np.asarray(leaf, np.float32)
+                                       - np.asarray(firsts[path], np.float32)))
+            for path, leaf in _leaves(last)}
+
+
+def _leaves(tree) -> list[tuple[str, object]]:
+    flat, _ = tree_util.tree_flatten_with_path(tree)
+    return [(tree_util.keystr(path), leaf) for path, leaf in flat]
 
 
 def rel(a, b) -> float:
@@ -53,6 +62,11 @@ def answer_numbers(answer, ref: dict) -> dict[str, float]:
 
 
 def change_gap(prog: dict[str, float], ref: dict[str, float]) -> float:
+    if set(prog) != set(ref):
+        raise ValueError(
+            "the program's and the reference's parameter trees differ: "
+            f"only the program's {sorted(set(prog) - set(ref))}, only the "
+            f"reference's {sorted(set(ref) - set(prog))}")
     floor = float(np.median(list(ref.values())))
     return max(abs(prog[k] - ref[k]) / max(ref[k], floor)
                for k in ref if ref[k] >= QUIET_LEAF * floor)

@@ -1,5 +1,6 @@
 """The paper's MNIST CNN (arXiv 2209.01750, Sec. VI-A.2) as a plain
-reference, and the FLOPs it needs per sample counted from its shapes.
+reference: its data, loss and accuracy, and the FLOPs it needs per sample
+counted from its shapes.
 
 5x5 conv 10 / 2x2 max pool / relu / 5x5 conv 20 / pool / relu / FC 50 /
 relu / dropout 0.5 / FC 10 / log-softmax; NHWC; 21,840 parameters.
@@ -32,6 +33,26 @@ def apply(p: dict, x, rng=None, train: bool = False):
     x = jax.nn.relu(x @ p["fc1_w"] + p["fc1_b"])
     x = dropout(x, 0.5, rng, train)
     return jax.nn.log_softmax(x @ p["fc2_w"] + p["fc2_b"], axis=-1)
+
+
+def loss(p: dict, x, y, rng):
+    """Mean negative log-likelihood of the labels, dropout on."""
+    logp = apply(p, x, rng=rng, train=True)
+    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
+
+
+def accuracy(p: dict, x, y):
+    pred = jnp.argmax(apply(p, x), axis=-1)
+    return jnp.mean((pred == y).astype(jnp.float32))
+
+
+def make_dataset(seed: int, config: dict):
+    """The synthetic image set that ``config["dataset"]`` names, of the
+    real sets' sizes unless ``n_train`` / ``n_test`` say otherwise."""
+    from bench.data.synthetic import make_dataset as synthetic
+
+    return synthetic(config["dataset"], seed, config.get("n_train"),
+                     config.get("n_test"))
 
 
 def forward_flops_per_sample() -> int:

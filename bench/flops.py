@@ -1,6 +1,8 @@
 """Model FLOPs of a federation, counted from shapes, and the chip's peak.
 
-Training counts forward and backward as three forward passes per sample, for
+A "sample" is the unit the batch counts: an image, or a sequence; each
+configuration module's ``forward_flops_per_sample()`` counts one. Training
+counts forward and backward as three forward passes per sample, for
 K * E * B samples an epoch; the in-scan eval is one forward pass per vehicle
 and eval sample on each evaluated epoch. Operations the program adds (the
 im2col patches, P1, the gossip mix) are not model FLOPs and are not counted.

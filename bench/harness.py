@@ -1,12 +1,32 @@
 """One run of one benchmark cell, driven by the names in ``BENCHMARK.json``.
 
-A cell names a configuration (``bench/configs/<config>.json``: the model and
-the job, with ``<config>.py`` beside it holding the plain reference model and
-its FLOP count) and a traffic mix (``bench/traffic/<traffic>.json``: the
-fleet, its road net and how the federation is run). Each metric is read by
+A cell names a configuration and a traffic mix. Whatever belongs to one
+model is in the configuration's own files, so a new model is new files and
+new entries, and nothing here changes:
+
+- ``bench/configs/<config>.json``: the job (``algorithm``, ``dataset``,
+  ``distribution``, ``lr``, ``local_steps``, ``batch_size``,
+  ``eval_samples``, ``p1_steps``, ``p1_step_size``,
+  ``shards_per_vehicle``) and, optionally, ``"program"``: further fields
+  of the program's ``SimulationConfig`` (a ``model``, say), set as they
+  stand. A key that is no such field, or one that ``sim_config`` already
+  sets from the job or the traffic, ends the run before any data is made:
+  the program and the reference have to run the same job.
+- ``bench/configs/<config>.py``: the plain reference model. It defines
+  ``init(key)``, one vehicle's parameters as the program's own parameter
+  tree, path for path (``bench/check.py`` compares them leaf by leaf);
+  ``loss(params, x, y, rng)``, the mean training loss of a batch (per
+  sample or per token); ``accuracy(params, x, y)``, the eval accuracy of
+  a batch (argmax, or next-token); ``make_dataset(seed, config)``, the
+  data, with the fields of ``bench.data.synthetic.Dataset`` (``train_y``
+  the per-sample label the balanced non-IID partition sorts on: for a
+  token set, a per-sequence domain); and ``forward_flops_per_sample()``
+  (``bench/flops.py``).
+
+The traffic mix (``bench/traffic/<traffic>.json``) holds the fleet, its
+road net and how the federation is run. Each metric is read by
 ``bench/metrics/<metric>.py``; the limits that decide ``correct`` are in
-``bench/limits/<workload>.json``. Nothing here names a cell: a new
-configuration, mix, metric or cell is new files and new entries.
+``bench/limits/<workload>.json``. Nothing here names a cell.
 
 The timed path is the program's own entry: ``repro.fed.engine.build_context``
 once, then ``run_with_context`` for whole federations back to back, each on
@@ -111,12 +131,34 @@ def register_road_net(cell: Cell) -> str:
     return name
 
 
+def program_fields(cell: Cell, set_here: dict) -> dict:
+    """The configuration's ``"program"`` object. A key that is no field of
+    the program's ``SimulationConfig``, or one in ``set_here`` (what the
+    harness sets from the job and the traffic, which the reference runs
+    too), is a ``SystemExit`` that names it."""
+    from dataclasses import fields
+
+    from repro.fed.engine import SimulationConfig
+
+    extra = cell.config.get("program", {})
+    source = f"bench/configs/{cell.config_name}.json"
+    known = {f.name for f in fields(SimulationConfig)}
+    for bad, why in ((set(extra) - known,
+                      "no field of the program's SimulationConfig"),
+                     (set(extra) & set(set_here),
+                      "fields the harness sets from the job and the traffic")):
+        if bad:
+            raise SystemExit(f"{source}: \"program\" names {sorted(bad)}, {why}")
+    return extra
+
+
 def sim_config(cell: Cell, seed: int, d_max: int = 0):
-    """The program's ``SimulationConfig`` for this cell and seed."""
+    """The program's ``SimulationConfig`` for this cell and seed, with the
+    configuration's ``program`` fields beside those set here."""
     from repro.fed.engine import SimulationConfig
 
     c, t = cell.config, cell.traffic
-    return SimulationConfig(
+    here = dict(
         algorithm=c["algorithm"], dataset=c["dataset"],
         distribution=c["distribution"], lr=c["lr"],
         local_steps=c["local_steps"], batch_size=c["batch_size"],
@@ -128,6 +170,7 @@ def sim_config(cell: Cell, seed: int, d_max: int = 0):
         mobility=t["mobility"], contact_format=t["contact_format"],
         mixing_backend=t["mixing_backend"], backend=t["backend"],
         d_max=d_max, seed=seed)
+    return SimulationConfig(**here, **program_fields(cell, here))
 
 
 def reference_job(cell: Cell):
@@ -146,10 +189,8 @@ def reference_job(cell: Cell):
 
 
 def make_data(cell: Cell, seed: int):
-    from bench.data.synthetic import make_dataset
-
-    return make_dataset(cell.config["dataset"], seed,
-                        cell.config.get("n_train"), cell.config.get("n_test"))
+    """The cell's data from ``seed``, as the configuration module makes it."""
+    return cell.model.make_dataset(seed, cell.config)
 
 
 class CompileCounter:
@@ -285,8 +326,8 @@ def setup(cell: Cell, seed: int, t_start: float) -> Run:
     shapes; ``setup_s`` runs from ``t_start``."""
     from repro.fed import engine, topology
 
+    probe_cfg = sim_config(cell, seed)  # a bad program field exits here
     data = make_data(cell, seed)
-    probe_cfg = sim_config(cell, seed)
     net = topology.make_road_network(probe_cfg.road_net)
     d_max = max(cell.traffic["d_max_floor"], engine.probe_d_max(probe_cfg, net))
     cfg = sim_config(cell, seed, d_max)
@@ -340,10 +381,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     t_start = time.perf_counter() if t_start is None else t_start
     cell = load_cell(root, workload)
     counter = CompileCounter()
-    run = setup(cell, seed, t_start)
-    run.setup_misses, run.setup_compile_s = counter.misses, counter.compile_s
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
+        run = setup(cell, seed, t_start)
+        run.setup_misses, run.setup_compile_s = counter.misses, counter.compile_s
         if trace:
             jax.profiler.start_trace(trace_dir)
         timed_window(run, seconds, counter, traced=trace)

@@ -97,8 +97,17 @@ def kl_bits(states, target):
     return jnp.mean(jnp.sum(terms, axis=-1))
 
 
+def _cast(a, dtype):
+    """``a`` on the device, in ``dtype`` if it is floating point; integer
+    data (token ids) keeps its type."""
+    a = jnp.asarray(a)
+    return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a
+
+
 class Federation:
-    """The reference for one (model, job, dataset, seed)."""
+    """The reference for one (model, job, dataset, seed). The model is a
+    configuration module (``bench/harness.py`` names what it defines); the
+    reference uses its ``init``, ``loss`` and ``accuracy``."""
 
     def __init__(self, model, job: Job, data, seed: int, dtype=jnp.float32,
                  block: int = 25):
@@ -109,9 +118,9 @@ class Federation:
                           seed)
         self.table = jnp.asarray(table, jnp.int32)
         self.target = jnp.full((k,), 1.0 / k, dtype)   # equal shards
-        self.x = jnp.asarray(data.train_x).astype(dtype)
+        self.x = _cast(data.train_x, dtype)
         self.y = jnp.asarray(data.train_y)
-        self.eval_x = jnp.asarray(data.test_x[: job.eval_samples]).astype(dtype)
+        self.eval_x = _cast(data.test_x[: job.eval_samples], dtype)
         self.eval_y = jnp.asarray(data.test_y[: job.eval_samples])
         pos, adj = mobility.grid(job.grid_side, job.grid_spacing)
         fleet = mobility.Manhattan(pos, adj, k, job.epoch_duration, seed)
@@ -121,17 +130,13 @@ class Federation:
         self.key = key
         self.init_params = model.init(kinit)
 
-    def _loss(self, p, x, y, rng):
-        logp = self.model.apply(p, x, rng=rng, train=True)
-        return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
-
     def _train_vehicle(self, args):
         p, xs, ys, key = args
         lr = jnp.asarray(self.job.lr, self.dtype)
 
         def step(p, inp):
             x, y, r = inp
-            loss, g = jax.value_and_grad(self._loss)(p, x, y, r)
+            loss, g = jax.value_and_grad(self.model.loss)(p, x, y, r)
             return jax.tree_util.tree_map(lambda w, d: w - lr * d, p, g), loss
 
         rs = jax.random.split(key, xs.shape[0])
@@ -163,11 +168,8 @@ class Federation:
 
     @partial(jax.jit, static_argnums=0)
     def _accuracy(self, params, x, y):
-        def one(p):
-            pred = jnp.argmax(self.model.apply(p, x), axis=-1)
-            return jnp.mean((pred == y).astype(jnp.float32))
-
-        return jax.lax.map(one, params, batch_size=self.block)
+        return jax.lax.map(lambda p: self.model.accuracy(p, x, y), params,
+                           batch_size=self.block)
 
     def run(self, epochs: int | None = None) -> dict:
         """Run the first ``epochs`` epochs (all by default). Returns the

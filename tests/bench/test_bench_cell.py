@@ -32,7 +32,17 @@ def test_result_line_keys(root):
 
 def test_new_config_mix_and_metric_are_found_by_name(root):
     """A new configuration, traffic mix and per-layer metric are files and
-    BENCHMARK.json entries; nothing else changes."""
+    BENCHMARK.json entries; nothing else changes.
+
+    A configuration is ``bench/configs/<config>.json`` and
+    ``<config>.py``. The module defines ``init`` (the program's own
+    parameter tree, path for path), ``loss``, ``accuracy``,
+    ``make_dataset(seed, config)`` and ``forward_flops_per_sample``. Its
+    JSON may hold a ``"program"`` object of the program's
+    ``SimulationConfig`` fields that the harness does not set itself. This
+    test adds a copy of the MNIST CNN; ``test_bench_config_contract.py``
+    adds one with its own data and program fields, and a token model with
+    nested parameters."""
     bench = root / "bench"
     (bench / "metrics" / "answers_counted.py").write_text(
         "def read(run):\n    return run.federations\n")
